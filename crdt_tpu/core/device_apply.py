@@ -30,7 +30,7 @@ into columnar tensors and applied as one vectorized applyUpdate").
 
 from __future__ import annotations
 
-from crdt_tpu.compat import enable_x64
+from jax import enable_x64
 
 from typing import Dict, List, Optional, Tuple
 

@@ -29,7 +29,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 
-from crdt_tpu.compat import enable_x64
+from jax import enable_x64
 import jax.numpy as jnp
 import numpy as np
 

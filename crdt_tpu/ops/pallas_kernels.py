@@ -44,7 +44,7 @@ import os
 
 import jax
 
-from crdt_tpu.compat import enable_x64
+from jax import enable_x64
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -541,36 +541,58 @@ def seg_argmax_scan(client: jnp.ndarray, flags: jnp.ndarray, *,
 NULL_I32 = -1
 
 
+# input rows walked per grid step: one (chunk,) int32 block of targets
+# DMA'd into SMEM (32 KiB), so SMEM use is fixed whatever the width
+_SCATTER_CHUNK = 8192
+
+
 def _stream_scatter_kernel(pos_ref, out_ref):
     """Permutation scatter: out[pos[i]] = i for in-range targets.
 
-    One program, whole block in VMEM; the fori_loop walks the input
-    once doing dynamic scalar stores — sequential, but each store is a
-    VMEM write with zero HBM traffic, and the targets are unique by
-    construction (per-segment DFS ranks + exclusive segment offsets),
-    so there is no ordering hazard. Rows routed past the output width
-    (invalid / padding) fall out via the bounds predicate.
+    Grid over input chunks; each step's targets sit in SMEM, where a
+    scalar read is cheap, and the whole output block stays resident in
+    VMEM across the grid (one writeback at the end). Mosaic refuses
+    scalar stores to VMEM, so each target is written as an aligned
+    (8, 128) tile: load, one-slot select, store. Sequential, but every
+    access is on-chip, and the targets are unique by construction
+    (per-segment DFS ranks + exclusive segment offsets), so there is no
+    ordering hazard. Rows routed past the output width (invalid /
+    padding) fall out via the bounds predicate.
     """
-    out_ref[:] = jnp.full(out_ref.shape, NULL_I32, jnp.int32)
-    n_in = pos_ref.shape[0] * pos_ref.shape[1]
-    limit = jnp.int32(out_ref.shape[0] * out_ref.shape[1])
+    step = pl.program_id(0)
     # explicit i32 scalars: the kernel body may be traced outside the
     # wrapper's enable_x64(False) window, where a weak python literal
     # promotes to i64 and breaks the i32 index arithmetic
+    chunk = jnp.int32(pos_ref.shape[0])
     lanes = jnp.int32(_LANES)
+    subl = jnp.int32(_SUBLANES)
+    limit = jnp.int32(out_ref.shape[0] * out_ref.shape[1])
+    tile = (_SUBLANES, _LANES)
+    sub = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
 
-    def body(i, _):
-        p = pos_ref[i // lanes, i % lanes]
+    @pl.when(step == 0)
+    def _():
+        out_ref[:] = jnp.full(out_ref.shape, NULL_I32, jnp.int32)
+
+    base = step * chunk
+
+    def body(j, carry):
+        p = pos_ref[j]
 
         @pl.when((p >= 0) & (p < limit))
         def _():
-            out_ref[p // lanes, p % lanes] = i
+            row = p // lanes
+            r0 = pl.multiple_of((row // subl) * subl, _SUBLANES)
+            hit = (sub == row - r0) & (lane == p % lanes)
+            cur = out_ref[pl.ds(r0, _SUBLANES), :]
+            out_ref[pl.ds(r0, _SUBLANES), :] = jnp.where(
+                hit, base + j, cur
+            )
 
-        return jnp.int32(0)  # explicit: a weak `0` promotes to i64
-        #                      under an x64-tracing caller and breaks
-        #                      the loop carry
+        return carry
 
-    jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_in), body, jnp.int32(0))
+    jax.lax.fori_loop(jnp.int32(0), chunk, body, jnp.int32(0))
 
 
 def stream_scatter_jnp(pos: jnp.ndarray, n_out: int) -> jnp.ndarray:
@@ -595,7 +617,10 @@ def stream_scatter(pos: jnp.ndarray, n_out: int, *,
     is the static :func:`converge_kernel_mode` decision."""
     if mode == "jnp":
         return stream_scatter_jnp(pos, n_out)
-    pos2 = _rows2d(pos.astype(jnp.int32))
+    n = pos.shape[0]
+    chunk = min(_SCATTER_CHUNK, _pad_len(n, _SUBLANES * _LANES))
+    pos1 = jnp.pad(pos.astype(jnp.int32), (0, _pad_len(n, chunk) - n),
+                   constant_values=-1)
     opad = _pad_len(n_out, _SUBLANES * _LANES)
     with enable_x64(False):
         out = pl.pallas_call(
@@ -603,8 +628,14 @@ def stream_scatter(pos: jnp.ndarray, n_out: int, *,
             out_shape=jax.ShapeDtypeStruct(
                 (opad // _LANES, _LANES), jnp.int32
             ),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            grid=(pos1.shape[0] // chunk,),
+            in_specs=[pl.BlockSpec((chunk,), lambda s: (s,),
+                                   memory_space=pltpu.SMEM)],
+            out_specs=pl.BlockSpec((opad // _LANES, _LANES),
+                                   lambda s: (0, 0)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
             interpret=(mode == "interpret"),
-        )(pos2)
+        )(pos1)
     return out.reshape(-1)[:n_out]

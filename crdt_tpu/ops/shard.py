@@ -23,7 +23,7 @@ segment granularity across the mesh:
    (layout-only), then every shard's eight sections are padded to
    COMMON bucket sizes and narrow-encoded with ONE shared encoding
    tuple, giving a [K, L] block a single compiled program serves.
-3. **Converge** — ONE ``compat.shard_map`` program
+3. **Converge** — ONE ``jax.shard_map`` program
    (:func:`crdt_tpu.parallel.gossip.make_packed_shard_step`): each
    device runs the full sortless fused converge on its shard; the
    only inter-chip traffic is the **boundary exchange** — the
@@ -57,7 +57,7 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
-from crdt_tpu.compat import enable_x64
+from jax import enable_x64
 from crdt_tpu.obs.profiling import device_annotation
 from crdt_tpu.obs.tracer import get_tracer
 from crdt_tpu.ops.device import (

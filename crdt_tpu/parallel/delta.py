@@ -31,7 +31,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from crdt_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from crdt_tpu.ops import statevec

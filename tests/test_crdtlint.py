@@ -1675,7 +1675,7 @@ def test_cl7xx_partial_shard_map_body_is_traced():
     import os
     from functools import partial
 
-    from crdt_tpu.compat import shard_map
+    from jax import shard_map
 
     @partial(shard_map, mesh=None)
     def step(x):

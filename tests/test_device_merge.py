@@ -8,6 +8,10 @@ encoded full state — on every workload class, and that the acceptance
 swarms converge with the device path enabled (VERDICT r1 item #1).
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -381,40 +385,48 @@ class TestDeviceModePlumbing:
         assert scalar.c["m"]["k"] == "A"
 
 
-class TestCompilationCacheHook:
-    """The local-CPU escape hatch suppresses the persistent compile
-    cache through jax's PRIVATE reset hook. If a jax upgrade removes
-    it, suppression silently no-ops and the SIGILL hazard (XLA:CPU AOT
-    artifacts persisted from an accelerator-backed process) returns —
-    so the hook's presence is pinned here, and its absence must warn
-    loudly instead of degrading in silence (ADVICE r5)."""
+class TestCompileCachePlacement:
+    """The persistent compile cache: JAX_COMPILATION_CACHE_DIR when
+    set (and no other directory), else ``<checkout>/.jax_cache``;
+    CPU-pinned processes keep none in the checkout."""
 
-    def test_reset_hook_present(self):
-        """Fails loudly when a jax upgrade removes the private hook
-        crdt_tpu.ops.device._cache_singleton_reset depends on."""
-        from jax._src import compilation_cache as cc
-
-        assert callable(getattr(cc, "reset_cache", None)), (
-            "jax._src.compilation_cache.reset_cache is gone: update "
-            "crdt_tpu.ops.device's cache suppression for this jax "
-            "version (silent no-op = SIGILL hazard)"
-        )
-
-    def test_missing_hook_warns_once_and_reports_failure(self, monkeypatch):
-        """With the hook absent, _cache_singleton_reset must return
-        False (callers then skip suppression) and emit its one-time
-        RuntimeWarning instead of pretending the reset happened."""
-        import warnings
-
-        from jax._src import compilation_cache as cc
-
+    def test_env_dir_wins(self, tmp_path):
         from crdt_tpu.ops import device
 
-        monkeypatch.delattr(cc, "reset_cache")
-        monkeypatch.setattr(device, "_RESET_HOOK_WARNED", False)
-        with pytest.warns(RuntimeWarning, match="reset_cache"):
-            assert device._cache_singleton_reset(None) is False
-        # second call: degraded mode already announced, no new warning
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            assert device._cache_singleton_reset(None) is False
+        env = {device.CACHE_ENV: str(tmp_path), "JAX_PLATFORMS": "tpu"}
+        assert device.compile_cache_dir(env) == str(tmp_path)
+
+    def test_unset_is_checkout_cache(self):
+        from crdt_tpu.ops import device
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert device.CHECKOUT_CACHE == os.path.join(root, ".jax_cache")
+        assert device.compile_cache_dir({}, "") == device.CHECKOUT_CACHE
+
+    def test_cpu_pinned_keeps_none(self):
+        from crdt_tpu.ops import device
+
+        assert device.compile_cache_dir({"JAX_PLATFORMS": "cpu"}) is None
+
+    def test_artifacts_only_in_env_dir(self, tmp_path):
+        """A fresh CPU process with the variable set writes its
+        compiled artifacts there, and nothing into the checkout."""
+        from crdt_tpu.ops import device
+
+        before = (set(os.listdir(device.CHECKOUT_CACHE))
+                  if os.path.isdir(device.CHECKOUT_CACHE) else set())
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+        code = ("import crdt_tpu.ops.device as d, jax, jax.numpy as jnp;"
+                "assert jax.config.jax_compilation_cache_dir == "
+                f"{str(tmp_path)!r};"
+                "jax.block_until_ready(jax.jit(lambda x: x * 3 + 1)"
+                "(jnp.arange(64)))")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                       check=True, timeout=120)
+        assert os.listdir(tmp_path), "no artifact in the env directory"
+        after = (set(os.listdir(device.CHECKOUT_CACHE))
+                 if os.path.isdir(device.CHECKOUT_CACHE) else set())
+        assert after == before

@@ -77,7 +77,7 @@ class TestStage:
         # the round-12 satellite: the map chain runs at MAP-BUCKET
         # width (mirroring the seq compact block), so a seq-heavy
         # union must get a map bucket far below the padded kernel
-        # width — the ~100-180ms lever ROOFLINE round 5 priced
+        # width
         n = 600
         cols = _cols(n, clients=np.ones(n), seq=True)
         cols["key_id"][:8] = 0  # 8 map rows in a 600-row union

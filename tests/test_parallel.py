@@ -196,3 +196,14 @@ def test_hierarchical_2d_mesh_matches_flat_gossip():
         fleet_out_sizes(R, N, R + 2, 256), flat, hier,
     ):
         np.testing.assert_array_equal(a, b, err_msg=f"{name} diverges")
+
+
+def test_make_mesh_refuses_more_devices_than_exist():
+    """A mesh smaller than asked would run a different program than
+    the one reported: make_mesh raises instead of truncating."""
+    import jax
+
+    n = len(jax.devices())
+    assert make_mesh(n).devices.size == n
+    with pytest.raises(ValueError, match="only"):
+        make_mesh(n + 1)

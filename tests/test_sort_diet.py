@@ -98,7 +98,8 @@ class TestSegArgmaxScan:
 
 
 class TestStreamScatter:
-    @pytest.mark.parametrize("n", [1, 5, 128, 700, 8 * 128 + 9])
+    @pytest.mark.parametrize("n", [1, 5, 128, 700, 8 * 128 + 9,
+                                   2 * pk._SCATTER_CHUNK + 77])
     def test_permutation_round_trip(self, n):
         rng = np.random.default_rng(n)
         pos = rng.permutation(n).astype(np.int32)

@@ -15,9 +15,8 @@ classes survive any amount of per-function review because they are
   (``converge_fetch`` / ``xfer_fetch`` / ``block_until_ready``),
   native KV / socket IO (``kv_*`` / ``udp_*`` ABI calls,
   ``subprocess.run``), ``Future.result()`` / ``Thread.join()`` /
-  ``queue.get`` / ``time.sleep`` — each can stall for the tunnel's
-  25–110 ms (or forever) while every other thread piles up on the
-  lock. Checked through the same STRONG closure, so a with-block that
+  ``queue.get`` / ``time.sleep`` — each can stall for milliseconds
+  (or forever) while every other thread piles up on the lock. Checked through the same STRONG closure, so a with-block that
   calls a helper whose callee blocks is still caught.
 - **CL803 — guarded-field inconsistency.** For thread-shared classes
   (any class with a method reachable from a ``Thread``/
@@ -102,9 +101,9 @@ class LockDisciplineChecker(Checker):
             "collapse the pair into one lock."
         ),
         "CL802": (
-            "A lock held across a blocking call (a tunnel fetch is "
-            "25-110 ms, a native build is seconds, Future.result "
-            "can be forever) serializes every other thread behind "
+            "A lock held across a blocking call (a device fetch "
+            "waits on the device, a native build is seconds, "
+            "Future.result can be forever) serializes every other thread behind "
             "IO they don't need. The classic outage shape: one slow "
             "dispatch, and the whole decode pool queues on a memo "
             "lock.\n"
