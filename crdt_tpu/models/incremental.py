@@ -67,6 +67,7 @@ import numpy as np
 from jax import enable_x64
 from crdt_tpu.codec import native
 from crdt_tpu.core.ids import DeleteSet
+from crdt_tpu.obs.tracer import get_tracer
 from crdt_tpu.ops.device import bucket_pow2
 from crdt_tpu.ops import packed as pk
 
@@ -573,12 +574,14 @@ class IncrementalReplay:
         """Decode an update batch into the engine's columnar format
         WITHOUT touching replica state: the multi-doc server's
         admissibility probe decodes once, then feeds the same dec to
-        :meth:`apply_decoded` (or discards it and cold-replays)."""
+        :meth:`apply_decoded` (or discards it and cold-replays).
+        Traced as ``decode``, the span the cold route's decode feeds."""
         if isinstance(blobs, (bytes, bytearray)):
             blobs = [bytes(blobs)]
-        return native.dedup_columns(
-            native.decode_updates_columns_any(list(blobs))
-        )
+        with get_tracer().span("decode"):
+            return native.dedup_columns(
+                native.decode_updates_columns_any(list(blobs))
+            )
 
     def delta_admissible(self, dec) -> bool:
         """Would this decoded batch admit WHOLE — no row stashed — so
@@ -1295,8 +1298,6 @@ class IncrementalReplay:
             plo, phi = self.evicted_ranges.get(c, (lo, hi))
             self.evicted_ranges[c] = (min(plo, lo), max(phi, hi))
         if evicted:
-            from crdt_tpu.obs.tracer import get_tracer
-
             get_tracer().count("engine.pending_evictions", len(evicted))
 
     def take_evicted_ranges(self) -> Dict[int, Tuple[int, int]]:
@@ -1309,7 +1310,8 @@ class IncrementalReplay:
     def cache(self) -> dict:
         """The plain-JSON view, flushed on read: rounds only mark
         touched segments dirty, so a replica that is never read pays
-        no materialization (crdt.js's `c` equivalent)."""
+        no materialization (crdt.js's `c` equivalent). The rebuild is
+        traced as ``engine.flush``."""
         if self.pool is not None and self.pool.has_pending(self):
             # a deferred pooled round must settle before the rebuild
             # reads winners/orders
@@ -1317,7 +1319,8 @@ class IncrementalReplay:
         if self._dirty:
             dirty, self._dirty = self._dirty, set()
             try:
-                self._rebuild_cache(dirty)
+                with get_tracer().span("engine.flush"):
+                    self._rebuild_cache(dirty)
             except BaseException:
                 # a failed rebuild must not mark the segments clean:
                 # the JSON view would stay permanently stale while

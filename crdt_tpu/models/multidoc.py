@@ -650,7 +650,15 @@ class MultiDocServer:
         the resident engines, promotions for warm docs without one,
         bin-packed cold dispatch batches for the rest (see module
         doc). One tick fully drains the dirty set — fairness decides
-        WHO goes first, the row cap decides how many dispatches."""
+        WHO goes first, the row cap decides how many dispatches.
+
+        Traced as the span ``tick``; each phase is a child span
+        ``tick.<phase>``, so ``tick``'s self time is the work outside
+        every phase (the scans and gauges)."""
+        with get_tracer().span("tick"):
+            return self._tick()
+
+    def _tick(self) -> TickReport:
         self.ticks += 1
         tl = get_timeline()
         tl.tick_begin(self.ticks)
@@ -760,13 +768,14 @@ class MultiDocServer:
         # the ingest-to-served clock closes at the tick boundary,
         # not at each batch's settle (a reader sees tick state)
         if self._served_buf:
-            t_served = time.perf_counter()
-            for tenant, stamps in self._served_buf:
-                self.slo.served(
-                    tenant, (t_served - t for t in stamps)
-                )
-            self._served_buf.clear()
-            self.slo.publish_worst()
+            with tl.phase("slo"):
+                t_served = time.perf_counter()
+                for tenant, stamps in self._served_buf:
+                    self.slo.served(
+                        tenant, (t_served - t for t in stamps)
+                    )
+                self._served_buf.clear()
+                self.slo.publish_worst()
         tl.tick_end()
         if tracer.enabled:
             tracer.count("tenant.docs_converged", len(dirty))

@@ -48,7 +48,9 @@ def decode(blobs: Sequence[bytes]) -> Dict:
 
 def stage(dec: Dict) -> Tuple[Dict[str, np.ndarray], DeleteSet]:
     """Kernel-facing columns + merged delete set."""
-    return native.kernel_columns(dec), native.ds_from_triples(dec["ds"])
+    with get_tracer().span("stage"):
+        return (native.kernel_columns(dec),
+                native.ds_from_triples(dec["ds"]))
 
 
 def converge(cols: Dict[str, np.ndarray], *,

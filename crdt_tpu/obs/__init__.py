@@ -47,8 +47,9 @@ replicas x 100k ops needs to be *seen*, not just claimed):
   LRU protection, dispatch pacing, checkpoint cadence), every
   decision in a bounded auditable ledger served at ``/control``.
 - :mod:`crdt_tpu.obs.profiling` — ``jax_profile`` (device trace
-  capture that cannot leak a running profiler) and per-dispatch
-  ``device_annotation`` XProf annotations.
+  capture that cannot leak a running profiler) and the cached
+  ``TraceAnnotation`` resolver every enabled tracer span opens
+  (``crdt.<span>``), plus ``device_annotation`` for code with no span.
 
 See README "Observability" / "Observability v2" for the
 metric/span/event name registry; ``tools/obsq.py`` is the offline

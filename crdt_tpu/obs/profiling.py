@@ -8,12 +8,15 @@
   whose jax lacks a profiler (or ``ProfileOptions`` — absent in the
   pinned jax 0.4.x) degrade with a clear ``RuntimeError`` instead of
   an opaque ``AttributeError`` mid-setup.
-- :func:`device_annotation` is the per-dispatch annotation seam: a
-  ``jax.profiler.TraceAnnotation`` context manager when available (so
-  XProf timelines attribute each converge dispatch / streaming shard
-  to its phase), a shared no-op otherwise. Resolution is cached after
-  the first call; the steady-state cost without a profiler is one
-  global check.
+- :func:`device_annotation` is the annotation seam for code with no
+  tracer span of its own (the streaming executor's per-shard
+  bracket): a ``jax.profiler.TraceAnnotation`` context manager when
+  available, a shared no-op otherwise. Every enabled tracer span
+  opens the same annotation as ``crdt.<span name>``
+  (:class:`crdt_tpu.obs.tracer.Tracer`), so the program's phases land
+  on the host plane of any running capture, on the device ops' clock.
+  Resolution is cached after the first call (:func:`annotation_cls`);
+  the steady-state cost without a profiler is one global check.
 """
 
 from __future__ import annotations
@@ -25,8 +28,9 @@ _NULL_CTX = nullcontext()  # reusable/reentrant stdlib no-op
 _annotation_cls: Optional[object] = None  # None = unresolved, False = absent
 
 
-def device_annotation(name: str):
-    """Context manager annotating enclosed dispatches for XProf."""
+def annotation_cls():
+    """The profiler's ``TraceAnnotation`` class, resolved once; False
+    where this jax has no profiler."""
     global _annotation_cls
     if _annotation_cls is None:
         try:
@@ -35,9 +39,15 @@ def device_annotation(name: str):
             _annotation_cls = jax.profiler.TraceAnnotation
         except Exception:
             _annotation_cls = False
-    if not _annotation_cls:
+    return _annotation_cls
+
+
+def device_annotation(name: str):
+    """Context manager annotating enclosed dispatches for XProf."""
+    cls = annotation_cls()
+    if not cls:
         return _NULL_CTX
-    return _annotation_cls(name)
+    return cls(name)
 
 
 @contextmanager

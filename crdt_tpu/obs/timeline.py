@@ -22,9 +22,11 @@ export as Chrome/Perfetto trace-event JSON (:meth:`TickTimeline.
 to_perfetto` — ``ui.perfetto.dev`` renders a serve() run as a
 zoomable timeline with the dispatch windows on their own track), or
 as plain dicts (:meth:`records`). Disabled by default; when disabled
-every hook is a single attribute check and :meth:`phase` returns one
-shared no-op context manager — the same free-when-off contract as the
-tracer. The record-building methods are called only from the single
+every hook is a single attribute check. :meth:`phase` times through
+the tracer's span ``tick.<name>`` (so one timing feeds the histogram,
+the profiler trace and this ring) and returns one shared no-op
+context manager when both are off — the same free-when-off contract
+as the tracer. The record-building methods are called only from the single
 tick thread; the ring itself is locked so ``/timeline`` scrapes and
 ``records()`` reads are safe from any thread.
 
@@ -63,20 +65,32 @@ def overlap_of(lanes: Dict[str, float], wall_s: float) -> float:
 
 
 class _PhaseCM:
-    __slots__ = ("_tl", "_name", "_t0")
+    """A phase recorded into the ring. Where the tracer is on, its
+    span ``tick.<name>`` does the timing and the ring takes the span's
+    own stamps; otherwise the phase times itself."""
 
-    def __init__(self, tl: "TickTimeline", name: str):
+    __slots__ = ("_tl", "_name", "_span", "_t0")
+
+    def __init__(self, tl: "TickTimeline", name: str, span):
         self._tl = tl
         self._name = name
+        self._span = span
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        if self._span is None:
+            self._t0 = time.perf_counter()
+        else:
+            self._span.__enter__()
         return None
 
     def __exit__(self, *exc):
-        self._tl.add_phase(
-            self._name, self._t0, time.perf_counter()
-        )
+        sp = self._span
+        if sp is None:
+            t0, t1 = self._t0, time.perf_counter()
+        else:
+            sp.__exit__(*exc)
+            t0, t1 = sp.t0, sp.t1
+        self._tl.add_phase(self._name, t0, t1)
         return False
 
 
@@ -112,11 +126,17 @@ class TickTimeline:
         }
 
     def phase(self, name: str):
-        """Context manager timing one host phase of the current tick
-        (no-op when disabled or outside a tick)."""
+        """Context manager timing one host phase of the current tick:
+        the tracer's span ``tick.<name>`` (histogram, self time and the
+        profiler annotation) when the tracer is on, and the ring's
+        record when this timeline is on and inside a tick. A shared
+        no-op when both are off."""
+        tracer = get_tracer()
+        # crdtlint: emits=tick.control,tick.prepare,tick.fair_order,tick.route,tick.pool,tick.settle,tick.pack,tick.unpack,tick.ingest,tick.slo
+        span = tracer.span("tick." + name) if tracer.enabled else None
         if not self.enabled or self._cur is None:
-            return _NULL_PHASE
-        return _PhaseCM(self, name)
+            return _NULL_PHASE if span is None else span
+        return _PhaseCM(self, name, span)
 
     def add_phase(self, name: str, t0: float, t1: float) -> None:
         if not self.enabled or self._cur is None:

@@ -14,11 +14,19 @@ latencies (p50/p90/p99) per phase, not just means — the difference
 between "converge averaged 12 ms" and "one dispatch in a hundred
 stalls 400 ms".
 
+``span`` is the program's one span primitive. An enabled span also
+opens ``jax.profiler.TraceAnnotation("crdt.<name>")`` (resolved once
+by :func:`crdt_tpu.obs.profiling.annotation_cls`), so a profiler
+capture shows the phase on its host plane, on the device ops' clock;
+and it keeps a per-thread stack of open spans, so ``report()`` gives
+each span's ``self_s``: its total less the time its child spans on
+the same thread cover.
+
 The public surface is a strict superset of the old tracer:
 ``get_tracer() / set_tracer / span / count / gauge /
 counters(prefix) / report / to_json / reset`` all behave identically
 (``report()`` keeps ``count/total_s/mean_s/max_s`` per span and adds
-``min_s/p50_s/p90_s/p99_s/buckets``). New: ``observe(name, seconds)``
+``min_s/p50_s/p90_s/p99_s/buckets/self_s``). New: ``observe(name, seconds)``
 records a duration measured elsewhere (e.g. propagation lag stamped
 by a trace id) into the same histogram machinery, and ``count`` /
 ``gauge`` accept a ``labels`` dict rendered Prometheus-style into the
@@ -34,6 +42,8 @@ import time
 from bisect import bisect_left
 from contextlib import nullcontext
 from typing import Any, Dict, Optional
+
+from crdt_tpu.obs import profiling as _profiling
 
 # base-2 bucket upper edges, 1us floor: bucket k holds durations in
 # (edge[k-1], edge[k]] — an observation exactly AT an edge lands in
@@ -131,18 +141,46 @@ _NULL_SPAN = nullcontext()
 
 
 class _LiveSpan:
-    __slots__ = ("_tracer", "_name", "_t0")
+    """One enabled span: timed on ``perf_counter`` (``t0``/``t1``),
+    bracketed by the profiler annotation, pushed on its thread's
+    stack so that its parent's self time leaves it out."""
+
+    __slots__ = ("_tracer", "_name", "_ann", "_stack", "_child", "t0",
+                 "t1")
 
     def __init__(self, tracer: "Tracer", name: str):
         self._tracer = tracer
         self._name = name
+        self._child = 0.0
+        self._ann = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._stack = self._tracer._stack()
+        self._stack.append(self)
+        cls = _profiling._annotation_cls
+        if cls is None:
+            cls = _profiling.annotation_cls()
+        # the annotation is built only while a capture records: with
+        # no profiler session running it would record nothing
+        if cls and cls.is_enabled():
+            self._ann = cls("crdt." + self._name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return None
 
     def __exit__(self, *exc):
-        self._tracer.observe(self._name, time.perf_counter() - self._t0)
+        self.t1 = time.perf_counter()
+        dt = self.t1 - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = self._stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # closed out of order
+            stack.remove(self)
+        if stack:
+            stack[-1]._child += dt
+        self._tracer._record(self._name, dt, dt - self._child)
         return False
 
 
@@ -178,8 +216,10 @@ class Tracer:
         self.enabled = enabled
         self._lock = threading.Lock()
         self._spans: Dict[str, _Span] = {}
+        self._self_s: Dict[str, float] = {}
         self._counters: Dict[str, int] = {}
         self._gauges: Dict[str, float] = {}
+        self._local = threading.local()  # .stack: open spans
 
     # -- phases ----------------------------------------------------------
     def span(self, name: str):
@@ -187,16 +227,28 @@ class Tracer:
             return _NULL_SPAN
         return _LiveSpan(self, name)
 
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
     def observe(self, name: str, seconds: float) -> None:
         """Record an externally measured duration into ``name``'s
-        histogram (same aggregate a ``span`` produces)."""
+        histogram (same aggregate a ``span`` produces; it has no
+        children, so all of it is self time)."""
         if not self.enabled:
             return
+        self._record(name, seconds, seconds)
+
+    def _record(self, name: str, seconds: float, self_s: float) -> None:
         with self._lock:
             s = self._spans.get(name)
             if s is None:
                 s = self._spans[name] = Histogram()
             s.add(seconds)
+            self._self_s[name] = self._self_s.get(name, 0.0) + self_s
 
     def quantile(self, name: str, q: float) -> float:
         """Bucket-resolution quantile of one span's histogram (0.0
@@ -244,7 +296,8 @@ class Tracer:
         embedded evidence all read."""
         with self._lock:
             spans = {
-                k: s.summary() for k, s in sorted(self._spans.items())
+                k: dict(s.summary(), self_s=self._self_s.get(k, 0.0))
+                for k, s in sorted(self._spans.items())
             }
             return {
                 "spans": spans,
@@ -258,6 +311,7 @@ class Tracer:
     def reset(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._self_s.clear()
             self._counters.clear()
             self._gauges.clear()
 

@@ -33,6 +33,7 @@ prescribes.
 from __future__ import annotations
 
 import os
+import threading
 from functools import partial
 from typing import Dict, NamedTuple, Optional
 
@@ -59,7 +60,6 @@ from crdt_tpu.ops.device import (
     xfer_put,
 )
 from crdt_tpu.ops.lww import map_winners
-from crdt_tpu.obs.profiling import device_annotation
 from crdt_tpu.obs.tracer import get_tracer
 
 # host-side packing limits for the composite segment key:
@@ -2126,6 +2126,34 @@ def _plan_args(plan: PackedPlan) -> dict:
     )
 
 
+_SEEN_SHAPES: set = set()  # compile keys dispatched in this process
+_SEEN_LOCK = threading.Lock()
+
+
+def _note_shape(program: str, arrays, args: dict, plan: PackedPlan,
+                mode: str) -> None:
+    """Count ``converge.new_shape`` once per first dispatch of a
+    compile key of the fused converge (its static arguments and its
+    operands' shapes): a dispatch that compiles inside a measured
+    window then names its program, its doubling rounds and its plan
+    length. The key set is the process's, as jit's own cache is."""
+    shapes = tuple((a.shape, str(a.dtype)) for a in arrays)
+    key = (program, shapes, plan.encs, mode) + tuple(sorted(args.items()))
+    with _SEEN_LOCK:
+        if key in _SEEN_SHAPES:
+            return
+        _SEEN_SHAPES.add(key)
+    tracer = get_tracer()
+    if tracer.enabled:
+        tracer.count("converge.new_shape", labels={
+            "program": program,
+            "rank_rounds": plan.rank_rounds,
+            "map_rounds": plan.map_rounds,
+            "plan": "+".join(f"{dt}[{','.join(map(str, sh))}]"
+                             for sh, dt in shapes),
+        })
+
+
 def _put_mat(plan: PackedPlan):
     """A matrix plan's ONE upload through the xfer seam, with the
     per-section width/savings record made at the same moment — never
@@ -2190,19 +2218,20 @@ def converge_async(plan: PackedPlan):
     fetch."""
     args = _plan_args(plan)
     mode = kernel_mode_for(plan.map_bucket, plan.seq_bucket)
-    # span = enqueue cost (the dispatch is async); the XProf
-    # annotation brackets the jitted call so device timelines
-    # attribute the fused kernel to the converge phase. The staged
-    # buffers are DONATED to the program (matrix upload through the
-    # xfer seam, eager sections at stage time): one plan, one
-    # dispatch.
-    with get_tracer().span("converge.dispatch"), \
-            device_annotation("crdt.converge.dispatch"), \
-            enable_x64(True):
+    # span = enqueue cost (the dispatch is async); its profiler
+    # annotation (crdt.converge.dispatch) brackets the jitted call so
+    # device timelines attribute the fused kernel to the converge
+    # phase. The staged buffers are DONATED to the program (matrix
+    # upload through the xfer seam, eager sections at stage time):
+    # one plan, one dispatch.
+    with get_tracer().span("converge.dispatch"), enable_x64(True):
         if plan.dev:
+            _note_shape("_converge_rows", plan.dev, args, plan, mode)
             out = _converge_rows(*plan.dev, **args,
                                  encs=plan.encs, mode=mode)
         else:
+            _note_shape("_converge_packed", (plan.mat,), args, plan,
+                        mode)
             out = _converge_packed(
                 _put_mat(plan), **args,
                 encs=plan.encs, mode=mode,
@@ -2219,8 +2248,7 @@ def converge_fetch(handle) -> PackedResult:
     ``xfer.d2h`` histogram records pure transfer time (previously the
     wait was folded in and the fetch cost was unattributable)."""
     plan, out = handle
-    with get_tracer().span("converge.fetch"), \
-            device_annotation("crdt.converge.fetch"):
+    with get_tracer().span("converge.fetch"):
         jax.block_until_ready(out)  # execution wait, not transfer
         return _assemble_result(
             plan, xfer_fetch(out, label="converge.out")
@@ -2262,6 +2290,7 @@ def converge(plan: PackedPlan,
             jax.block_until_ready(plan.dev)  # eager uploads land
             mark("upload_wait", t0)
             t0 = _t.perf_counter()
+            _note_shape("_converge_rows", plan.dev, args, plan, mode)
             out = _converge_rows(*plan.dev, **args,          # 1 dispatch
                                  encs=plan.encs, mode=mode)
             jax.block_until_ready(out)
@@ -2272,6 +2301,8 @@ def converge(plan: PackedPlan,
             jax.block_until_ready(dev_mat)                   # 1 transfer
             mark("upload_wait", t0)
             t0 = _t.perf_counter()
+            _note_shape("_converge_packed", (plan.mat,), args, plan,
+                        mode)
             out = _converge_packed(dev_mat, **args,          # 1 dispatch
                                    encs=plan.encs, mode=mode)
             jax.block_until_ready(out)

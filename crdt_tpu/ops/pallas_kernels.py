@@ -178,6 +178,7 @@ def _ds_mask_call(cl2, ckh2, ckl2, dcl, dsh, dsl, deh, delo, interpret):
             ],
             out_specs=pl.BlockSpec(block, lambda i: (i, 0), memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="ds_mask",
         )(dcl, dsh, dsl, deh, delo, cl2, ckh2, ckl2)
 
 
@@ -304,6 +305,7 @@ def _sv_deficit_call(svs, interpret):
                 (_DEF_TI, _DEF_TJ), lambda i, j, k: (i, j), memory_space=pltpu.VMEM
             ),
             interpret=interpret,
+            name="sv_deficit",
         )(svs, svs)
 
 
@@ -534,6 +536,7 @@ def seg_argmax_scan(client: jnp.ndarray, flags: jnp.ndarray, *,
             ],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             interpret=(mode == "interpret"),
+            name="seg_argmax_scan",
         )(cl2, fl2)
     return out.reshape(-1)[:n]
 
@@ -637,5 +640,6 @@ def stream_scatter(pos: jnp.ndarray, n_out: int, *,
                 dimension_semantics=("arbitrary",)
             ),
             interpret=(mode == "interpret"),
+            name="stream_scatter",
         )(pos1)
     return out.reshape(-1)[:n_out]

@@ -58,7 +58,6 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from jax import enable_x64
-from crdt_tpu.obs.profiling import device_annotation
 from crdt_tpu.obs.tracer import get_tracer
 from crdt_tpu.ops.device import (
     NULLI,
@@ -635,9 +634,7 @@ def converge_async(splan: ShardPlan):
     mode = packed.kernel_mode_for(splan.map_bucket, splan.seq_bucket)
     step = _get_step(splan, mode)
     tracer = get_tracer()
-    with tracer.span("converge.dispatch"), \
-            device_annotation("crdt.shard.dispatch"), \
-            enable_x64(True):
+    with tracer.span("converge.dispatch"), enable_x64(True):
         record_staged_widths(
             splan.widths, splan.block.nbytes, splan.wide_bytes
         )
@@ -669,8 +666,7 @@ def converge_fetch(handle) -> ShardResult:
 
     splan, out, gsv = handle
     S2, B2 = splan.num_segments, splan.seq_bucket
-    with get_tracer().span("converge.fetch"), \
-            device_annotation("crdt.shard.fetch"):
+    with get_tracer().span("converge.fetch"):
         jax.block_until_ready(out)  # execution wait, not transfer
         h = xfer_fetch(out, label="shard.out")
         gs = xfer_fetch(gsv, label="shard.sv")

@@ -1,0 +1,11 @@
+"""Snapshot (models/replay.compact: the replayed union encoded as one
+blob): the program's ``compact`` span, host clock, in ms per replay of
+the window."""
+
+
+def read(rd):
+    s = rd["spans"]["spans"].get("compact")
+    n = rd["run"].get("replays")
+    if not s or not s["count"] or not n or "total_s" not in s:
+        return None
+    return s["total_s"] * 1e3 / n

@@ -1,0 +1,11 @@
+"""Codec (IncrementalReplay.decode_delta, and models/replay.decode on the
+cold route): the program's ``decode`` span, host clock, in ms per tick
+of the window."""
+
+
+def read(rd):
+    s = rd["spans"]["spans"].get("decode")
+    n = rd["run"].get("ticks")
+    if not s or not s["count"] or not n or "total_s" not in s:
+        return None
+    return s["total_s"] * 1e3 / n

@@ -27,6 +27,7 @@ import numpy as np
 HOT_PATH_SPANS = (
     "decode", "pack", "converge.dispatch", "converge.fetch",
     "gather", "materialize", "compact", "persist", "persist.compact",
+    "stage", "tick",
 )
 
 

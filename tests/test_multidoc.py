@@ -736,3 +736,48 @@ def test_multidoc_stage_counts_docs_packed():
             "converge.docs_packed") == 3
     finally:
         set_tracer(Tracer(enabled=False))
+
+
+def test_tick_phases_are_tracer_spans_and_the_ring_still_records():
+    """A delta tick on a resident doc is traced end to end: ``tick``,
+    its phases as ``tick.<phase>`` (route, slo among them), the delta's
+    ``decode`` and the lazy read's ``engine.flush``; each phase also
+    lands in the tick timeline's ring when the timeline is on."""
+    from crdt_tpu.obs.timeline import (
+        TickTimeline, get_timeline, set_timeline,
+    )
+
+    tracer = set_tracer(Tracer(enabled=True))
+    old_tl = get_timeline()
+    tl = set_timeline(TickTimeline(enabled=True))
+    try:
+        s = DocStream(3)
+        srv = MultiDocServer()
+        for t in range(3):  # cold, promotion, delta
+            srv.submit("d", s.delta(12 if t == 0 else 3))
+            srv.tick()
+            srv.cache("d")
+        assert srv.is_resident("d")
+        tracer.reset()
+        srv.submit("d", s.delta(3))
+        rep = srv.tick()
+        assert rep.delta_docs == 1
+        srv.cache("d")
+        spans = tracer.report()["spans"]
+        for name in ("tick", "tick.prepare", "tick.route", "tick.settle",
+                     "tick.slo", "decode", "engine.flush"):
+            assert spans.get(name, {}).get("count", 0) > 0, name
+        tick = spans["tick"]
+        phases = sum(v["total_s"] for k, v in spans.items()
+                     if k.startswith("tick."))
+        assert tick["self_s"] == pytest.approx(
+            tick["total_s"] - phases, abs=1e-9)
+        last = tl.records()[-1]
+        assert {"prepare", "route", "settle", "slo"} <= {
+            n for n, _, _ in last["phases"]}
+        # the ring takes the span's own stamps: one timing for both
+        route = [b - a for n, a, b in last["phases"] if n == "route"]
+        assert route == [spans["tick.route"]["total_s"]]
+    finally:
+        set_tracer(Tracer(enabled=False))
+        set_timeline(old_tl)

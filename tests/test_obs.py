@@ -561,7 +561,10 @@ class TestTracerEdges:
         for v in (1e-5, 2e-4, 3e-3):
             tr.observe("x", v)
             h.add(v)
-        assert tr.report()["spans"]["x"] == h.summary()
+        # the report is the histogram's summary plus the span's self
+        # time (all of an observed duration)
+        assert tr.report()["spans"]["x"] == dict(h.summary(),
+                                                 self_s=h.total_s)
 
 
 # ---------------------------------------------------------------------------
@@ -1201,3 +1204,138 @@ class TestObsqExitCodes:
         assert rc == 2
         err = capsys.readouterr().err
         assert "not JSONL" in err
+
+
+# ---------------------------------------------------------------------------
+# one span primitive: profiler annotation + self time
+# ---------------------------------------------------------------------------
+
+
+class _StubAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs each
+    open and close by name."""
+
+    log: list = []
+    capturing = True
+
+    def __init__(self, name):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return _StubAnnotation.capturing
+
+    def __enter__(self):
+        _StubAnnotation.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        _StubAnnotation.log.append(("exit", self.name))
+        return False
+
+
+class TestSpanPrimitive:
+    @pytest.fixture
+    def stub(self, monkeypatch):
+        from crdt_tpu.obs import profiling
+
+        _StubAnnotation.log = []
+        monkeypatch.setattr(profiling, "_annotation_cls", _StubAnnotation)
+        return _StubAnnotation.log
+
+    def test_enabled_span_opens_the_crdt_annotation(self, stub):
+        t = Tracer(enabled=True)
+        with t.span("tick"):
+            with t.span("tick.route"):
+                pass
+        assert stub == [("enter", "crdt.tick"), ("enter", "crdt.tick.route"),
+                        ("exit", "crdt.tick.route"), ("exit", "crdt.tick")]
+        assert t.report()["spans"]["tick"]["count"] == 1
+
+    def test_disabled_span_opens_no_annotation(self, stub):
+        t = Tracer(enabled=False)
+        with t.span("tick"):
+            pass
+        assert stub == []
+
+    def test_no_annotation_while_no_capture_records(self, stub,
+                                                     monkeypatch):
+        monkeypatch.setattr(_StubAnnotation, "capturing", False)
+        t = Tracer(enabled=True)
+        with t.span("tick"):
+            pass
+        assert stub == []
+        assert t.report()["spans"]["tick"]["count"] == 1
+
+    def test_self_time_is_duration_less_children(self, stub):
+        t = Tracer(enabled=True)
+        with t.span("outer"):
+            time.sleep(0.004)
+            for _ in range(2):
+                with t.span("mid"):
+                    time.sleep(0.002)
+                    with t.span("leaf"):
+                        time.sleep(0.003)
+        sp = t.report()["spans"]
+        assert sp["leaf"]["self_s"] == sp["leaf"]["total_s"]
+        assert sp["mid"]["self_s"] == pytest.approx(
+            sp["mid"]["total_s"] - sp["leaf"]["total_s"], abs=1e-12)
+        assert sp["outer"]["self_s"] == pytest.approx(
+            sp["outer"]["total_s"] - sp["mid"]["total_s"], abs=1e-12)
+        assert sp["outer"]["self_s"] >= 0.004
+        # observe() has no children: all of it is self time
+        t.observe("ext", 0.5)
+        assert t.report()["spans"]["ext"]["self_s"] == 0.5
+
+    def test_self_time_is_per_thread(self, stub):
+        """A span open on one thread is no parent of a span on
+        another: each thread keeps its own stack."""
+        t = Tracer(enabled=True)
+        gate = threading.Barrier(2, timeout=10)
+
+        def lone():
+            with t.span("lone"):
+                gate.wait()   # the other thread's spans run now
+                gate.wait()
+
+        def nested():
+            gate.wait()
+            with t.span("outer"):
+                with t.span("inner"):
+                    time.sleep(0.003)
+            gate.wait()
+
+        ths = [threading.Thread(target=f) for f in (lone, nested)]
+        for th in ths:
+            th.start()
+        for th in ths:
+            th.join(timeout=20)
+            assert not th.is_alive()
+        sp = t.report()["spans"]
+        assert sp["lone"]["self_s"] == sp["lone"]["total_s"]
+        assert sp["lone"]["total_s"] >= sp["outer"]["total_s"]
+        assert sp["outer"]["self_s"] == pytest.approx(
+            sp["outer"]["total_s"] - sp["inner"]["total_s"], abs=1e-12)
+
+    def test_disabled_span_allocates_nothing(self):
+        import tracemalloc
+
+        import crdt_tpu.obs.tracer as tr
+
+        t = Tracer(enabled=False)
+        assert t.span("a") is t.span("b")
+        with t.span("warm"):
+            pass
+        flt = [tracemalloc.Filter(True, tr.__file__)]
+        tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot().filter_traces(flt)
+            for _ in range(1000):
+                with t.span("decode"):
+                    pass
+            after = tracemalloc.take_snapshot().filter_traces(flt)
+        finally:
+            tracemalloc.stop()
+        grown = [s for s in after.compare_to(before, "lineno")
+                 if s.size_diff > 0]
+        assert not grown, grown
+        assert not t.report()["spans"]

@@ -351,3 +351,24 @@ class TestStagedRightOrdering:
 def eng_cache(eng):
     """Visible JSON of an engine — same shape replay_trace's cache has."""
     return eng.to_json()
+
+
+def test_new_shape_counted_once_per_compile_key(monkeypatch):
+    """``converge.new_shape`` names each fused-converge compile key
+    (program, rounds, plan length) at its first dispatch in the
+    process, and never again for the same key."""
+    from crdt_tpu.obs import Tracer, set_tracer
+
+    monkeypatch.setattr(packed, "_SEEN_SHAPES", set())
+    tracer = set_tracer(Tracer(enabled=True))
+    try:
+        for n in (8, 8, 40):
+            packed.converge(packed.stage(_cols(n)))
+        got = tracer.counters("converge.new_shape")
+        assert len(got) == 2 and set(got.values()) == {1}, got
+        for key in got:
+            assert 'program="_converge_packed"' in key
+            assert "rank_rounds=" in key and "map_rounds=" in key
+            assert 'plan="int16[' in key
+    finally:
+        set_tracer(Tracer(enabled=False))

@@ -47,6 +47,8 @@ NAMESPACES = frozenset({
     # round 24 (fleet serving): the live-migration recovery ladder
     # (the `fleet.*` ownership counters were already listed above)
     "migration",
+    # the server tick's phases as tracer spans (tick.route, tick.slo, ...)
+    "tick",
 })
 
 # backticked dotted names that share a namespace but are NOT metrics
