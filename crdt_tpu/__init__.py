@@ -22,7 +22,13 @@ A from-scratch rebuild of the capability surface of ypear/crdt
 
 __version__ = "0.1.0"
 
-from crdt_tpu.core.ids import ID, StateVector, DeleteSet  # noqa: F401
+from crdt_tpu.utils.heap import pin_heap
+
+# before the package's first large allocation: one allocator policy for
+# every process that imports the package (crdt_tpu/utils/heap.py)
+pin_heap()
+
+from crdt_tpu.core.ids import ID, StateVector, DeleteSet  # noqa: E402,F401
 
 
 def __getattr__(name):

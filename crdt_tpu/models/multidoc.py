@@ -135,6 +135,7 @@ from crdt_tpu.obs.slo import SLOLedger
 from crdt_tpu.obs.timeline import get_timeline
 from crdt_tpu.obs.tracer import get_tracer
 from crdt_tpu.ops import packed
+from crdt_tpu.utils.heap import count_policy
 
 _MAX_ROWS_ENV = "CRDT_TPU_MT_MAX_ROWS"
 _PENDING_BYTES_ENV = "CRDT_TPU_MT_PENDING_BYTES"
@@ -654,8 +655,11 @@ class MultiDocServer:
 
         Traced as the span ``tick``; each phase is a child span
         ``tick.<phase>``, so ``tick``'s self time is the work outside
-        every phase (the scans and gauges)."""
-        with get_tracer().span("tick"):
+        every phase (the scans and gauges). Each tick counts
+        ``heap.policy`` (``crdt_tpu/utils/heap.py``)."""
+        tracer = get_tracer()
+        count_policy(tracer)
+        with tracer.span("tick"):
             return self._tick()
 
     def _tick(self) -> TickReport:

@@ -28,6 +28,7 @@ import numpy as np
 from crdt_tpu.codec import native
 from crdt_tpu.core.ids import DeleteSet
 from crdt_tpu.obs.tracer import get_tracer
+from crdt_tpu.utils.heap import count_policy
 
 
 class ReplayResult(NamedTuple):
@@ -577,7 +578,9 @@ def replay_trace(
       ``"device"``, differential-tested byte-identical).
 
     All engines are differential-tested against each other and the
-    scalar oracle; ``ReplayResult.path`` records which one ran."""
+    scalar oracle; ``ReplayResult.path`` records which one ran. Each
+    call counts ``heap.policy`` (``crdt_tpu/utils/heap.py``)."""
+    count_policy(get_tracer())
     if route == "stream":
         from crdt_tpu.models.streaming import stream_replay
 

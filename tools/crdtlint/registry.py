@@ -49,6 +49,8 @@ NAMESPACES = frozenset({
     "migration",
     # the server tick's phases as tracer spans (tick.route, tick.slo, ...)
     "tick",
+    # the process's allocator policy (heap.policy{state=...})
+    "heap",
 })
 
 # backticked dotted names that share a namespace but are NOT metrics
