@@ -21,6 +21,7 @@ tests/test_models.py.
 
 from __future__ import annotations
 
+from itertools import chain
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -415,17 +416,27 @@ def rows_visible(
     return ~((pos >= 0) & (qk < ends[posc]))
 
 
+def delete_ranges(ds: DeleteSet) -> Tuple[np.ndarray, ...]:
+    """The normalized delete set as (client, start, end) int64 columns
+    for :func:`rows_visible` (one array build, no per-range tuples)."""
+    ds.normalize()
+    clients = list(ds.ranges)
+    se = np.fromiter(
+        chain.from_iterable(chain.from_iterable(ds.ranges.values())),
+        np.int64,
+    ).reshape(-1, 2)
+    per = np.fromiter((len(r) for r in ds.ranges.values()), np.int64,
+                      len(clients))
+    return np.repeat(np.asarray(clients, np.int64), per), se[:, 0], se[:, 1]
+
+
 def visible_mask(dec: Dict, rows: List[int], ds: DeleteSet) -> List[bool]:
     """Tombstone visibility for specific rows (vectorized)."""
     if not rows:
         return []
     idx = np.asarray(rows)
-    trip = list(ds.iter_all())  # normalized: disjoint, client-sorted
-    del_c = np.asarray([c for c, _, _ in trip], np.int64)
-    del_s = np.asarray([s for _, s, _ in trip], np.int64)
-    del_e = np.asarray([s + n for _, s, n in trip], np.int64)
     return list(rows_visible(
-        dec["client"][idx], dec["clock"][idx], del_c, del_s, del_e
+        dec["client"][idx], dec["clock"][idx], *delete_ranges(ds)
     ))
 
 
@@ -457,6 +468,10 @@ def assemble_cache(dec: Dict, ds: DeleteSet, win_rows, win_vis,
 
 def _assemble_cache(dec: Dict, ds: DeleteSet, win_rows, win_vis,
                     seq_orders) -> Tuple[dict, Dict[str, int]]:
+    """Column-wise assembly: one tombstone test over every sequence
+    row, visible winners grouped by parent with numpy, values copied
+    out in bulk. Only type rows (``kind == K_TYPE``) recurse, one by
+    one, into the collection they head."""
     from crdt_tpu.core.store import K_TYPE, TYPE_MAP
 
     keys = dec["keys"]
@@ -464,56 +479,91 @@ def _assemble_cache(dec: Dict, ds: DeleteSet, win_rows, win_vis,
     client, clock = dec["client"], dec["clock"]
     kind_col, tref = dec["kind"], dec["type_ref"]
     contents = dec["contents"]
+    taken = {"bulk": 0, "nested": 0}
 
-    # vectorized tombstone test for every sequence row at once (the
-    # per-row ds.contains walk was ~half of materialize at 100k ops)
-    all_seq_rows = sorted(
-        {int(r) for rows in seq_orders.values() for r in rows}
+    # every sequence row in one array, one visibility call; each
+    # spec keeps its slice of it
+    seq_span: Dict[Tuple, Tuple[int, int]] = {}
+    at = 0
+    for spec, rows in seq_orders.items():
+        seq_span[spec] = (at, at + len(rows))
+        at += len(rows)
+    seq = np.fromiter(
+        chain.from_iterable(seq_orders.values()), np.int64, at
     )
-    seq_vis = dict(
-        zip(all_seq_rows, visible_mask(dec, all_seq_rows, ds))
-    )
+    seq_vis = rows_visible(client[seq], clock[seq], *delete_ranges(ds))
 
-    # visible map winners grouped by their parent spec
-    map_groups: Dict[Tuple, Dict[str, int]] = {}
-    for row, vis in zip(win_rows, win_vis):
-        if not vis:
-            continue
-        map_groups.setdefault(parent_spec(dec, row), {})[
-            keys[kid[row]]
-        ] = row
+    # visible winners grouped by parent (root index, or the parent
+    # item's id): a stable sort keeps win_rows order inside a group,
+    # and groups run in the order of their first winner
+    win = np.asarray(win_rows, np.int64)[np.asarray(win_vis, bool)]
+    pr = dec["parent_root"][win].astype(np.int64)
+    is_item = pr < 0
+    pa = np.where(is_item, dec["parent_client"][win], pr)
+    pb = np.where(is_item, dec["parent_clock"][win], 0)
+    order = np.lexsort((np.arange(len(win)), pb, pa, is_item))
+    cut = np.flatnonzero(
+        (np.diff(pa[order]) != 0) | (np.diff(pb[order]) != 0)
+        | (np.diff(is_item[order].astype(np.int8)) != 0)
+    ) + 1
+    starts = np.r_[0, cut] if len(win) else cut
+    ends = np.r_[cut, len(win)]
+    heads = order[starts]  # each group's first winner, as a win index
 
-    def value_of(row: int, depth: int):
-        if kind_col[row] == K_TYPE:
-            spec = ("item", int(client[row]), int(clock[row]))
-            is_map = tref[row] == TYPE_MAP
-            return collection(spec, is_map, depth + 1)
-        return contents[row]
+    def values(rows: np.ndarray, depth: int) -> list:
+        out = [contents[i] for i in rows.tolist()]
+        nested = np.flatnonzero(kind_col[rows] == K_TYPE)
+        taken["bulk"] += len(rows) - len(nested)
+        taken["nested"] += len(nested)
+        for j in nested.tolist():
+            out[j] = nested_value(int(rows[j]), depth + 1)
+        return out
 
-    def collection(spec: Tuple, is_map: bool, depth: int):
+    def map_of(rows: np.ndarray, depth: int) -> dict:
+        # a repeated key keeps its first place and its last row's value
+        return dict(zip([keys[k] for k in kid[rows].tolist()],
+                        values(rows, depth)))
+
+    def seq_of(spec: Tuple, depth: int) -> list:
+        a, b = seq_span.get(spec, (0, 0))
+        return values(seq[a:b][seq_vis[a:b]], depth)
+
+    item_maps: Dict[Tuple[int, int], np.ndarray] = {}
+
+    def nested_value(row: int, depth: int):
         if depth > 64:
             return None  # malformed cyclic nesting: cut, don't recurse
-        if is_map:
-            return {
-                k: value_of(r, depth)
-                for k, r in map_groups.get(spec, {}).items()
-            }
-        return [
-            value_of(r, depth)
-            for r in seq_orders.get(spec, ())
-            if seq_vis[int(r)]
-        ]
+        ident = (int(client[row]), int(clock[row]))
+        if tref[row] == TYPE_MAP:
+            rows = item_maps.get(ident)
+            return {} if rows is None else map_of(rows, depth)
+        return seq_of(("item",) + ident, depth)
+
+    roots = dec["roots"]
+    root_maps = []
+    ix_group: Dict[str, int] = {}
+    for g in np.argsort(heads):
+        h, rows = heads[g], win[order[starts[g]:ends[g]]]
+        if is_item[h]:
+            item_maps[(int(pa[h]), int(pb[h]))] = rows
+        elif roots[pa[h]] == "ix":
+            # the reserved collection-kind index stays internal,
+            # exactly as the document API's `c` hides it
+            ix_group = dict(zip([keys[k] for k in kid[rows].tolist()],
+                                rows.tolist()))
+        else:
+            root_maps.append((roots[pa[h]], rows))
 
     cache: dict = {}
-    for spec in map_groups:
-        # the reserved collection-kind index stays internal, exactly
-        # as the document API's `c` hides it
-        if spec[0] == "root" and spec[1] != "ix":
-            cache[spec[1]] = collection(spec, True, 0)
+    for name, rows in root_maps:
+        cache[name] = map_of(rows, 0)
     for spec in seq_orders:
         if spec[0] == "root" and spec[1] not in cache:
-            cache[spec[1]] = collection(spec, False, 0)
-    return cache, map_groups.get(("root", "ix"), {})
+            cache[spec[1]] = seq_of(spec, 0)
+    tracer = get_tracer()
+    for path, n in taken.items():
+        tracer.count("materialize.rows", n, labels={"path": path})
+    return cache, ix_group
 
 
 def finish_cache(cache: dict, dec: Dict,

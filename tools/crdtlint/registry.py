@@ -51,6 +51,8 @@ NAMESPACES = frozenset({
     "tick",
     # the process's allocator policy (heap.policy{state=...})
     "heap",
+    # the cache assembly's row paths (materialize.rows{path=...})
+    "materialize",
 })
 
 # backticked dotted names that share a namespace but are NOT metrics
