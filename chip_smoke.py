@@ -9,10 +9,10 @@ Run from the root of a checkout, on a machine with a TPU:
 
 One chip runs two phases through the entry points users call:
 
-- **server**: ``MultiDocServer`` takes the bench's multitenant shape
-  (1000 docs x 64 ops + 4 docs x 4096 ops, v1 wire blobs), ticks until
-  clean, then serves steady-state rounds big enough for the device
-  route.
+- **server**: ``MultiDocServer`` takes the multitenant shape of
+  ``workloads.build_doc_trace`` (1000 docs x 64 ops + 4 docs x 4096
+  ops, v1 wire blobs), ticks until clean, then serves steady-state
+  rounds big enough for the device route.
 - **fan-in**: BASELINE config #5 (``build_trace(1000, 100)``, 100k ops)
   through ``replay_trace``, then the 16x union (1.6M ops) through
   ``stream_replay``.
@@ -115,11 +115,11 @@ def phase_server(n_small=1000, ops_small=64, n_big=4, ops_big=4096,
     """MultiDocServer on v1 blobs, ticked clean, then steady rounds of
     fresh writers on the big docs (``round_writers * round_ops`` ops
     per doc per round: above the host/device crossover)."""
-    import bench
+    import workloads
     from crdt_tpu.models.multidoc import MultiDocServer, cache_digest
     from crdt_tpu.ops import packed
 
-    docs = bench.build_doc_trace(n_small, ops_small, n_big, ops_big)
+    docs = workloads.build_doc_trace(n_small, ops_small, n_big, ops_big)
     srv = MultiDocServer()
     for d, bs in docs.items():
         srv.submit_many(d, bs)
@@ -137,9 +137,9 @@ def phase_server(n_small=1000, ops_small=64, n_big=4, ops_big=4096,
     delta_docs = promotions = 0
     for r in range(rounds):
         for j, d in enumerate(big):
-            bs = bench.build_trace(round_writers, round_ops,
-                                   seed=100 * r + j,
-                                   client_base=1000 * (r + 1))
+            bs = workloads.build_trace(round_writers, round_ops,
+                                       seed=100 * r + j,
+                                       client_base=1000 * (r + 1))
             docs[d] = docs[d] + bs
             srv.submit_many(d, bs)
         rep = srv.tick()
@@ -170,12 +170,12 @@ def phase_server(n_small=1000, ops_small=64, n_big=4, ops_big=4096,
 def phase_fanin(R=1000, K=100, scale=16) -> dict:
     """BASELINE config #5 through replay_trace, then the ``scale``x
     union through stream_replay."""
-    import bench
+    import workloads
     from crdt_tpu.models import replay_trace, stream_replay
 
-    blobs = bench.build_trace(R, K)
+    blobs = workloads.build_trace(R, K)
     check_replay("fan-in replay_trace", replay_trace(blobs), blobs)
-    blobs_l = bench.build_trace(R * scale, K, seed=1)
+    blobs_l = workloads.build_trace(R * scale, K, seed=1)
     check_replay("fan-in stream_replay", stream_replay(blobs_l), blobs_l)
     return {"ops": R * K, "union_ops": R * scale * K}
 
@@ -184,11 +184,11 @@ def phase_sharded(R=16000, K=100, chips=4) -> dict:
     """The union on the sharded route over ``chips`` devices, byte-
     identical to the same union converged on one device, and the
     sharded output spread over all of them."""
-    import bench
+    import workloads
     from crdt_tpu.models import replay as rp
     from crdt_tpu.ops import shard
 
-    blobs = bench.build_trace(R, K, seed=1)
+    blobs = workloads.build_trace(R, K, seed=1)
     cols, _ = rp.stage(rp.decode(blobs))
     n = len(cols["client"])
     check(shard.active_for(n), f"sharded: route off for {n} rows")

@@ -85,12 +85,7 @@ the staging seam), ``tenant.*`` counters/gauges (README
 "Observability" registry — round 15 adds ``tenant.delta_docs`` /
 ``delta_rows`` / ``promotions`` / ``delta_fallbacks`` /
 ``resident_evictions`` and the ``tenant.resident_bytes`` /
-``resident_docs`` gauges), and the ``bench.py --multitenant`` legs:
-round-14 packing (``docs_converged_per_s`` vs the one-dispatch-per-doc
-baseline) plus the round-15 steady-state leg (N ticks of small deltas
-on large resident docs vs the full-replay tick, ``steady.speedup``),
-both digest-asserted against the cold oracle and regression-gated in
-``tools/metrics_diff.py``.
+``resident_docs`` gauges).
 
 Observability v2 (round 18): every admitted blob is SLO-stamped at
 submit; the settle path closes its ingest-to-converged clock and the

@@ -13,8 +13,8 @@ path end to end:
   5. compact: one snapshot blob (the log squashed — what a fresh
      replica needs instead of the whole history).
 
-This is the library form of what ``bench.py`` measures; the benchmark
-imports these stages so the timed pipeline IS the product pipeline.
+The benchmark (``perfbench/``) times these stages through
+:func:`replay_trace`, so the timed pipeline IS the product pipeline.
 Differential-tested against the scalar document path in
 tests/test_models.py.
 """

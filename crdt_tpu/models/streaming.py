@@ -42,7 +42,7 @@ Phase accounting: ``phases`` (when passed) receives per-stage BUSY
 seconds summed across lanes, plus ``wall_s``, ``busy_sum_s``, and
 ``overlap_efficiency`` = (busy - wall) / (busy - max_stage): 0 means
 fully serial, 1 means the wall clock collapsed onto the single longest
-stage. ``bench.py`` publishes these for the scale run.
+stage.
 """
 
 from __future__ import annotations
@@ -109,8 +109,7 @@ def overlap_stats(phases: Dict[str, float], wall: float) -> Dict:
     lane's OCCUPANCY — host stages plus the device lane's
     non-overlapping converge span — and excludes blocked-wait
     diagnostics, so it reconstructs what the serial pipeline would
-    cost (cross-checked against the one-shot oracle's wall in
-    bench.py). ``overlap_efficiency`` is (busy - wall) /
+    cost. ``overlap_efficiency`` is (busy - wall) /
     (busy - max_stage) — the fraction of the maximally-hideable time
     that WAS hidden (1.0 = wall collapsed to the longest stage, 0.0 =
     fully serial); ``wall_vs_phases`` is the raw wall / sum-of-phases

@@ -83,9 +83,8 @@ class ControlLedger:
     Rows are plain JSON-ready dicts; :meth:`to_jsonl` renders them
     with sorted keys so a replayed controller's ledger compares
     byte-for-byte. When the ring is full the oldest row is dropped
-    and counted (``control.ledger_dropped`` — gated lower-is-better
-    in ``tools/metrics_diff.py``: a hot control loop that churns its
-    own audit trail is a finding, not a feature).
+    and counted (``control.ledger_dropped``: a hot control loop that
+    churns its own audit trail is a finding, not a feature).
     """
 
     def __init__(self, capacity: int = DEFAULT_LEDGER_CAPACITY):

@@ -1,8 +1,8 @@
 """Metrics export: Prometheus text exposition + JSON snapshot.
 
 Both read the ONE shared schema — ``Tracer.report()`` — so a scrape,
-a committed ``BENCH_OUT.json``, and an interactive ``report()`` all
-describe the same numbers with the same names:
+a saved snapshot, and an interactive ``report()`` all describe the
+same numbers with the same names:
 
 - counters  -> ``# TYPE <ns>_<name> counter`` (labels preserved:
   a tracer key ``name{k="v"}`` exposes as-is after sanitization)
@@ -130,8 +130,7 @@ def to_prometheus(report: Optional[Dict[str, Any]] = None,
 
 
 def snapshot_json(report: Optional[Dict[str, Any]] = None) -> str:
-    """The JSON snapshot: ``Tracer.report()`` serialized verbatim (the
-    same object ``bench.py`` embeds under ``"tracer"``)."""
+    """The JSON snapshot: ``Tracer.report()`` serialized verbatim."""
     if report is None:
         from crdt_tpu.obs.tracer import get_tracer
 

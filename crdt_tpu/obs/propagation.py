@@ -317,8 +317,8 @@ class PropagationLedger:
     :func:`set_propagation`), fed by the replica's send/receive seams
     when observability is on. Keeps route-tagged lag histograms and
     the wire-overhead accounting, mirrors everything into the
-    process-global tracer (so ``/metrics`` scrapes and BENCH_OUT
-    artifacts carry it), and reports as one JSON-ready dict."""
+    process-global tracer (so ``/metrics`` scrapes carry it), and
+    reports as one JSON-ready dict."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -32,7 +32,7 @@ tick thread; the ring itself is locked so ``/timeline`` scrapes and
 
 Tracer emission at each tick end (README "Observability v2"):
 gauges ``timeline.overlap_efficiency`` / ``timeline.stall_ms`` (the
-last tick's values — gateable in ``tools/metrics_diff.py``), counter
+last tick's values), counter
 ``timeline.ticks``.
 """
 

@@ -292,8 +292,7 @@ class Tracer:
     # -- reporting -------------------------------------------------------
     def report(self) -> Dict[str, Any]:
         """One plain JSON-ready dict — the shared schema the JSON
-        snapshot, the Prometheus exposition, and ``bench.py``'s
-        embedded evidence all read."""
+        snapshot and the Prometheus exposition both read."""
         with self._lock:
             spans = {
                 k: dict(s.summary(), self_s=self._self_s.get(k, 0.0))

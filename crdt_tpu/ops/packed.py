@@ -1399,10 +1399,7 @@ def _map_block(mkey, cend, rend, *, map_rounds: int, mode: str):
     min clock) member — the last child of the Yjs sibling order. The
     chain walk (deep key chains) stays pointer doubling.
 
-    ONE definition shared by :func:`_converge_packed_body` and the
-    bench ablation rig (``bench.kernel_ablation_leg``), so the gated
-    ``kernel_ablation.map_winners_ms`` numbers always time the
-    algorithm production runs."""
+    Called from :func:`_converge_packed_body`."""
     M = mkey.shape[0]
     mflag = jnp.where(mkey >= 0, mkey & 1, 1).astype(jnp.int32)
     mcl = jnp.where(mkey >= 0, mkey >> 1, NULLI).astype(jnp.int32)

@@ -45,8 +45,7 @@ fleet replay all take this route through :func:`active_for`.
 
 Evidence: ``shard.dispatches`` / ``shard.boundary_bytes`` /
 ``shard.seam_rows`` counters and the ``shard.shards`` gauge (README
-"Observability" registry; ``bench.py --multichip`` publishes the
-per-device-count scaling table).
+"Observability" registry).
 """
 
 from __future__ import annotations

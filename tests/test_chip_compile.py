@@ -73,11 +73,11 @@ def compile_ok(fn, *args, **kw):
 def plan_100k():
     """The north-star fan-in union (1000 replicas x 100 ops) staged as
     the fused converge's one matrix, on the host."""
-    import bench
+    import workloads
     from crdt_tpu.models import replay as rp
     from crdt_tpu.ops import packed
 
-    cols, _ = rp.stage(rp.decode(bench.build_trace(1000, 100)))
+    cols, _ = rp.stage(rp.decode(workloads.build_trace(1000, 100)))
     return cols, packed.stage(cols)
 
 

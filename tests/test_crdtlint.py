@@ -33,7 +33,7 @@ def lint(files, registry=None, baseline=None):
     """Lint {relpath: source} snippets with an empty default registry
     (synthetic runs opt into documented names explicitly)."""
     config = LintConfig(
-        repo_root="/synthetic", readme_path="", smoke_test_path="",
+        repo_root="/synthetic", readme_path="", registry_test_path="",
         baseline_path="/synthetic/absent.json",
     )
     return run_lint(
@@ -707,9 +707,9 @@ def test_syntax_error_is_a_finding():
 
 
 def test_run_lint_public_entry(tmp_path):
-    """The public run_lint() surface used by the CLI and bench."""
+    """The public run_lint() surface used by the CLI."""
     config = LintConfig(
-        repo_root=str(tmp_path), readme_path="", smoke_test_path="",
+        repo_root=str(tmp_path), readme_path="", registry_test_path="",
         baseline_path=str(tmp_path / "none.json"),
     )
     result = run_lint(

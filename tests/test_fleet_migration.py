@@ -580,3 +580,59 @@ class TestWire:
         fab.send("b", "a", b"not a frame")
         assert node.drain_inbox() == 1  # delivered, decode refused
         assert node.server._docs == {}
+
+
+# ---- three OS processes, and the placement loop's A/B --------------
+
+
+def test_three_process_rebalance():
+    """Three fleet servers in separate OS processes over sealed
+    loopback UDP (``tests/fleet_procs.py rebalance``), one crash-safe
+    live migration a -> c: exactly one server afterwards, every lease
+    on it, the losers' fences counted, no fork, no UDP failure."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(here, "fleet_procs.py"),
+         "rebalance"],
+        capture_output=True, text=True, timeout=300,
+        cwd=os.path.dirname(here),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    rb = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert rb["migrate_ok"] is True
+    assert rb["completed"] == 1
+    assert rb["servers"] == ["c"]
+    assert rb["served_digest"] == rb["seeded_digest"]
+    # b adopts the new owner from its ownership beacon
+    assert rb["leases"] == {p: [2, "c"] for p in MEMBERS}
+    assert rb["fence_rejects"]["a"] >= 1
+    assert sum(rb["fork_refused"].values()) == 0
+    assert sum(rb["udp_failed"].values()) == 0
+
+
+def test_placement_loop_ab_under_chaos():
+    """The placement loop on and off under the same flood, the same
+    dropped acks and duplicated commits, the same duplicated and
+    replayed advice: on, the flooder is moved and its burn recovers
+    within budget, with an identity doc migrated and its source killed
+    and revived; off, the squeeze alone never recovers. Never a
+    double serve; every untouched doc byte-identical across the two."""
+    from tests.fleet_procs import rebalance_leg
+
+    leg = rebalance_leg()
+    assert leg["all_identical"], leg["identical"]
+    assert leg["double_serves"] == 0 and leg["forks"] == 0
+    assert leg["fence_rejects"] > 0
+    assert leg["migrations"] >= 1
+    assert leg["migrations_completed"] >= 2
+    assert leg["migration_recoveries"] >= 1
+    assert leg["advice_dups"] > 0
+    assert leg["recovery_ticks"] is not None
+    assert leg["recovery_ticks"] <= leg["recovery_budget_ticks"]
+    assert leg["recovery_ticks_off"] is None
+    assert (leg["burn_end_off"] or 0.0) > 0.25

@@ -191,11 +191,11 @@ def test_one_replay_counts_the_policy_once():
     out = probe("""
 import jax
 jax.config.update("jax_enable_x64", True)
-import bench
+import workloads
 from crdt_tpu.models.replay import replay_trace
 from crdt_tpu.obs.tracer import Tracer, set_tracer
 tr = set_tracer(Tracer(enabled=True))
-res = replay_trace(bench.build_trace(4, 8, seed=3))
+res = replay_trace(workloads.build_trace(4, 8, seed=3))
 out["n_ops"] = res.n_ops
 out["policy"] = tr.counters("heap.policy")
 """)

@@ -415,12 +415,12 @@ def test_host_and_device_modes_converge_identically():
     segment ordering, zero device work) and forced-device rounds must
     land on the identical cache — the crossover rule may pick either
     side at any time."""
-    import bench as _bench  # the canonical workload generators
+    import workloads
 
-    base = _bench.build_trace(40, 40, seed=3)
+    base = workloads.build_trace(40, 40, seed=3)
     deltas = [
-        _bench.build_trace(4, 40, seed=60 + i, client_base=900 + 4 * i,
-                           map_frac=0.5)
+        workloads.build_trace(4, 40, seed=60 + i,
+                              client_base=900 + 4 * i, map_frac=0.5)
         for i in range(3)
     ]
     from crdt_tpu.models.incremental import IncrementalReplay

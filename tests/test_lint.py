@@ -108,7 +108,7 @@ def test_cli_runs_without_importing_jax():
 
 def _lint_snippet(path, src, registry=None):
     config = LintConfig(
-        repo_root="/synthetic", readme_path="", smoke_test_path="",
+        repo_root="/synthetic", readme_path="", registry_test_path="",
         baseline_path="/synthetic/absent.json",
     )
     return run_lint(
@@ -353,7 +353,7 @@ def test_checker_still_fires(code):
     if code == "CL503":
         config = LintConfig(
             repo_root="/synthetic", readme_path="",
-            smoke_test_path="",
+            registry_test_path="",
             baseline_path="/synthetic/absent.json",
         )
         result = run_lint(
@@ -549,7 +549,7 @@ def test_committed_baseline_has_no_stale_entries_audit():
 def _real_registry():
     return load_registry(
         os.path.join(REPO, "README.md"),
-        os.path.join(REPO, "tests", "test_bench_smoke.py"),
+        os.path.join(REPO, "tests", "test_registry.py"),
     )
 
 
@@ -846,28 +846,6 @@ def test_device_memo_caches_locked_under_threads():
     # have lost entries (the pre-lock failure mode was a silent
     # overwrite: wasted recompile, never detected)
     assert sorted(dev._pack_fns) == [1, 2, 3]
-
-
-def test_smoke_emit_skips_lint_pass(monkeypatch, tmp_path, capsys):
-    """Review regression: ``emit_result(path=None)`` (the smoke mode
-    every tier-1 run pays for) must not run the ~3s whole-tree lint
-    pass for a digest nothing reads; the artifact path still embeds
-    it."""
-    import bench
-
-    calls = []
-    monkeypatch.setattr(
-        bench, "lint_digest",
-        lambda: calls.append(1) or {"findings": 0, "open": 0},
-    )
-    out = {"metric": "toy"}
-    bench.emit_result(out, path=None)
-    assert not calls and "lint" not in out
-
-    out2 = {"metric": "toy"}
-    bench.emit_result(out2, path=str(tmp_path / "B.json"))
-    assert calls and out2["lint"] == {"findings": 0, "open": 0}
-    capsys.readouterr()
 
 
 # ---------------------------------------------------------------------------

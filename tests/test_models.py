@@ -324,7 +324,7 @@ class TestReplayRoutes:
     by the session-calibrated crossover and records its choice."""
 
     def test_all_routes_agree(self):
-        import bench as B
+        import workloads as B
 
         for builder in (B.build_trace, B.build_conflict_trace,
                         B.build_text_trace):
@@ -340,7 +340,7 @@ class TestReplayRoutes:
             assert rep.snapshot == dev.snapshot, builder.__name__
 
     def test_auto_records_its_choice(self):
-        import bench as B
+        import workloads as B
 
         blobs = B.build_trace(10, 10)
         res = replay_trace(blobs, route="auto")

@@ -2,8 +2,8 @@
 
 The suite runs on the CPU mesh (tests/conftest.py), so the kernels
 execute in interpreter mode — the same kernel bodies that compile via
-Mosaic on TPU (verified on hardware; bench.py exercises the compiled
-path). Each test drives the pallas function directly against the pure
+Mosaic on TPU (``tests/test_chip_compile.py`` compiles them for a
+described v5e; ``chip_smoke.py`` runs them on the chip). Each test drives the pallas function directly against the pure
 jnp implementation on identical inputs.
 """
 

@@ -230,7 +230,7 @@ def test_fence_counters_documented_in_registry():
 
     reg = load_registry(
         os.path.join(REPO, "README.md"),
-        os.path.join(REPO, "tests", "test_bench_smoke.py"),
+        os.path.join(REPO, "tests", "test_registry.py"),
     )
     for name in (
         "fleet.fence_rejects", "fleet.fork_refused",

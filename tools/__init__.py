@@ -1,2 +1,3 @@
-"""Repo tooling: bench diffing (`metrics_diff`), kernel profiling,
-and the static invariant checker (`tools.crdtlint`)."""
+"""Repo tooling: the flight-recorder query CLI (`obsq`), the tracer
+span cost probe (`span_cost`), and the static invariant checker
+(`tools.crdtlint`)."""

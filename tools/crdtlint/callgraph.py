@@ -21,8 +21,8 @@ defs (``outer.<locals>.inner``). Edges carry a **confidence**:
   thread-REACHABILITY (CL803's thread-shared-class discovery must not
   miss a class because a receiver was a local variable) and exactly
   wrong for closures. Name collisions (several classes defining the
-  method) are counted in ``stats()`` so the bench digest shows how
-  much of the graph is guessed.
+  method) are counted in ``stats()``, which shows how much of the
+  graph is guessed.
 
 Thread roots: ``threading.Thread(target=f)`` keywords,
 ``executor.submit(f, ...)`` / ``executor.map(f, ...)`` first

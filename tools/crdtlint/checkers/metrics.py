@@ -1,6 +1,6 @@
 """CL201/CL202/CL203: observability registry conformance (round 8).
 
-The README "Observability" tables and ``tests/test_bench_smoke.py``
+The README "Observability" tables and ``tests/test_registry.py``
 pin the span/counter/gauge and flight-recorder event registries as
 stable contracts. This checker diffs those registries against what
 the package actually emits, **both ways**:
@@ -57,7 +57,7 @@ class MetricsRegistryChecker(Checker):
     name = "metrics-registry"
     codes = {
         "CL201": "metric/event name emitted but absent from the "
-                 "documented registry (README / test_bench_smoke)",
+                 "documented registry (README / test_registry)",
         "CL202": "registry documents a name nothing emits",
         "CL203": "computed (non-literal) metric name outside the "
                  "allowlist",
@@ -65,9 +65,9 @@ class MetricsRegistryChecker(Checker):
     explain = {
         "CL201": (
             "A metric emitted but missing from the README registry "
-            "tables is invisible to reviewers and ungated by "
-            "metrics_diff — exactly the round-8 drift crdtlint was "
-            "built to stop.\n"
+            "tables is invisible to reviewers and unpinned by "
+            "tests/test_registry.py — exactly the round-8 drift "
+            "crdtlint was built to stop.\n"
             "Fix: add the name to the README Observability/Failure "
             "tables (backticked), or rename to an existing "
             "documented name."
@@ -94,7 +94,7 @@ class MetricsRegistryChecker(Checker):
         reg = ctx.shared.get("metric_registry")
         if reg is None:
             reg = load_registry(
-                ctx.config.readme_path, ctx.config.smoke_test_path
+                ctx.config.readme_path, ctx.config.registry_test_path
             )
             ctx.shared["metric_registry"] = reg
         # name -> first (path, line) that emits it

@@ -2,8 +2,8 @@
 
 Every H2D/D2H byte must flow through ``ops/device.py``'s
 ``xfer_put`` / ``xfer_fetch`` seam — that is what makes
-``xfer.h2d_bytes`` / ``xfer.d2h_bytes`` trustworthy enough for
-``tools/metrics_diff.py`` to gate on. A raw ``jax.device_put`` (or a
+``xfer.h2d_bytes`` / ``xfer.d2h_bytes`` trustworthy enough to
+read as bytes on the link. A raw ``jax.device_put`` (or a
 ``np.asarray(...)`` host-materialization of a dispatch result)
 anywhere else ships bytes the accounting never sees, and the diet
 silently rots.

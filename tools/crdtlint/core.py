@@ -16,9 +16,9 @@ Vocabulary:
   first ten lines silences a code for the whole file.
 - The **baseline** (``tools/crdtlint/baseline.json``) lists known,
   *justified* findings by fingerprint. Baselined findings don't fail
-  the run but are counted (``lint.findings`` rides the bench diff
-  table, lower-is-better — growing the baseline is visible). Every
-  entry must carry a non-empty ``justification``.
+  the run but are counted (``LintResult.total_raw`` — growing the
+  baseline is visible). Every entry must carry a non-empty
+  ``justification``.
 
 Checkers subclass :class:`Checker` and register in
 ``tools.crdtlint.checkers.ALL_CHECKERS``; each gets three hooks —
@@ -164,7 +164,7 @@ class LintConfig:
 
     repo_root: str = ""
     readme_path: Optional[str] = None     # metric/event registry prose
-    smoke_test_path: Optional[str] = None  # HOT_PATH_SPANS pin
+    registry_test_path: Optional[str] = None  # HOT_PATH_SPANS, REGISTRY
     baseline_path: Optional[str] = None
 
     def __post_init__(self):
@@ -175,11 +175,9 @@ class LintConfig:
         if self.readme_path is None:
             p = os.path.join(self.repo_root, "README.md")
             self.readme_path = p if os.path.exists(p) else None
-        if self.smoke_test_path is None:
-            p = os.path.join(
-                self.repo_root, "tests", "test_bench_smoke.py"
-            )
-            self.smoke_test_path = p if os.path.exists(p) else None
+        if self.registry_test_path is None:
+            p = os.path.join(self.repo_root, "tests", "test_registry.py")
+            self.registry_test_path = p if os.path.exists(p) else None
         if self.baseline_path is None:
             self.baseline_path = os.path.join(
                 os.path.dirname(os.path.abspath(__file__)),
@@ -267,14 +265,14 @@ class LintResult:
     baselined: List[Finding]
     stale_baseline: List[str]          # fingerprints with no live finding
     # analysis-layer evidence (round 16): the memoized call graph's
-    # size stats, embedded in the bench digest so graph growth/decay
-    # is visible next to the finding counts
+    # size stats, so graph growth/decay is visible next to the
+    # finding counts
     stats: Dict[str, object] = field(default_factory=dict)
 
     @property
     def total_raw(self) -> int:
-        """Every violation the checkers saw, suppressed or not — the
-        ``lint.findings`` bench metric (growing the baseline moves it)."""
+        """Every violation the checkers saw, suppressed or not (growing
+        the baseline moves it)."""
         return len(self.findings) + len(self.suppressed) + len(self.baselined)
 
     def open_by_family(self, families=("CL1", "CL2", "CL3", "CL4",
@@ -282,9 +280,8 @@ class LintResult:
                                        "CL9", "CL10",
                                        "CL11")) -> Dict[str, int]:
         """OPEN finding count per code family (``cl7`` counts every
-        CL7xx). The committed tree gates these at zero (tier-1), so
-        ``tools/metrics_diff.py`` sees any new open finding as a
-        regression with count semantics — no noise floor. Codes are
+        CL7xx); the committed tree holds every family at zero
+        (tier-1). Codes are
         ``CL`` + 3 digits (families cl1–cl9) or ``CL`` + 4 digits
         (the round-17 cl10/cl11 wire-taint families) — a CL1001 must
         count under ``cl10``, never under the donate family ``cl1``."""

@@ -9,8 +9,8 @@ Two sources of documented truth:
   metric (lowercase dotted name whose first segment is a known
   namespace), expanding the ``fault.drop/dup/delay`` slash shorthand
   and stripping ``{label=...}`` suffixes.
-- ``tests/test_bench_smoke.py`` — ``HOT_PATH_SPANS`` plus the literal
-  counter names its asserts pin.
+- ``tests/test_registry.py`` — ``HOT_PATH_SPANS`` plus every string
+  literal there that names a metric (the ``REGISTRY`` table).
 
 The registry conformance checker diffs these against the names the
 package actually emits, both ways.
@@ -31,7 +31,7 @@ NAMESPACES = frozenset({
     "xfer", "guard", "persist", "engine", "device", "replica",
     "router", "sentinel", "fleet", "gossip", "update", "sync",
     "probe", "ae", "beacon", "dial", "relay", "envelope", "fault",
-    "overload", "lint", "converge", "shard", "tenant",
+    "converge", "shard", "tenant",
     # round 18 (observability v2): the SLO ledger and the
     # tick-timeline profiler
     "slo", "timeline",
@@ -60,14 +60,7 @@ NAMESPACES = frozenset({
 NON_METRICS = frozenset({
     "replica.sentinel.events",   # Replica attribute, not a counter
     "router.stats",              # router's tracer-free stats dict
-    "overload.peak_inbox_bytes",  # BENCH_OUT section keys, gated by
-    "overload.shed_count",        # metrics_diff directly
     "fleet.leases",               # snapshot-store blob key (round 24)
-    "overload.shed_bytes",
-    "lint.findings",              # bench artifact keys (this tool's
-    "lint.open_by_family",        # own gated metrics and the round-16
-    "lint.callgraph",             # call-graph stats), not tracer names
-    "lint.callgraph.collisions",
     "shard.mat",                  # xfer_put call-site labels, not
     "shard.wire",                 # tracer names (they surface only as
     "shard.out",                  # {path=...} label values on the
@@ -150,9 +143,9 @@ def parse_readme(path: str, reg: Registry) -> None:
                 reg.add(name, kind, path, lineno)
 
 
-def parse_smoke_test(path: str, reg: Registry) -> None:
-    """Every string literal in the smoke test that names a registered
-    span/counter (HOT_PATH_SPANS entries, counter asserts)."""
+def parse_registry_test(path: str, reg: Registry) -> None:
+    """Every string literal in the registry test that names a
+    registered span/counter (HOT_PATH_SPANS, the REGISTRY table)."""
     with open(path, encoding="utf-8") as f:
         tree = ast.parse(f.read(), filename=path)
     for node in ast.walk(tree):
@@ -179,10 +172,10 @@ def parse_smoke_test(path: str, reg: Registry) -> None:
 
 
 def load_registry(readme_path: Optional[str],
-                  smoke_test_path: Optional[str]) -> Registry:
+                  registry_test_path: Optional[str]) -> Registry:
     reg = Registry()
     if readme_path:
         parse_readme(readme_path, reg)
-    if smoke_test_path:
-        parse_smoke_test(smoke_test_path, reg)
+    if registry_test_path:
+        parse_registry_test(registry_test_path, reg)
     return reg
